@@ -13,6 +13,8 @@ def main() -> None:
     from benchmarks import (blocking_locality, cnn_llm_layers, decode_serving,
                             fused_gemm, instruction_count, roofline,
                             table1_smm, table4_conv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sections = [
         ("Table 1 (SMM 512 speedups)", table1_smm.rows),
         ("Fig 1 (blocking locality)", blocking_locality.rows),

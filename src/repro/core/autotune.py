@@ -114,6 +114,12 @@ def _key(kind: str, fused: bool, m: int, n: int, k: int, backend: str,
     return f"{kind}|{f}|m{m}|n{n}|k{k}|{backend}"
 
 
+# Scoped VMEM the fused kernels' in-kernel int4 unpack (int32 nibble shifts
+# plus the row interleave) takes per unpacked weight element: the v5e
+# compiler asks for 33.3 MB at bk×bn = 4096×256 and 16.7 MB at 2048×256.
+_W4_UNPACK_VMEM_PER_ELEM = 32
+
+
 def _fits(kind: str, fused: bool, block: Tuple[int, int, int], k: int,
           a_in_bytes: int, budget: int = VMEM_BYTES // 2) -> bool:
     bm, bn, bk = block
@@ -121,12 +127,16 @@ def _fits(kind: str, fused: bool, block: Tuple[int, int, int], k: int,
     if fused:
         # Fused kernels hold the full (bm, K) activation row-panel in its
         # storage dtype plus the int8 working block, B double-buffered, the
-        # int32 accumulator, the f32 output tile and the (bm, 1) scales.
+        # int32 accumulator, the f32 output tile and the (bm, 1) scales;
+        # packed-int4 weights add the unpack temporaries, which must fit
+        # the scoped VMEM limit together with the rest.
         from repro.kernels.padding import round_up
         kp = round_up(k, bk)
         a = bm * kp * a_in_bytes + bm * bk
         b = 2 * (bk * bn * w_bits // 8)
-        return a + b + bm * bn * 8 + bm * 4 <= budget
+        need = a + b + bm * bn * 8 + bm * 4
+        unpack = _W4_UNPACK_VMEM_PER_ELEM * bk * bn if w_bits == 4 else 0
+        return need <= budget and need + unpack <= VMEM_BYTES
     return BlockConfig(bm, bn, bk).vmem_bytes(w_bits, a_bits) <= budget
 
 
@@ -376,36 +386,43 @@ PREFILL_PAGES_PER_STEP = (1, 2, 4, 8)
 
 def model_paged_prefill_time_s(kv_heads: int, head_dim: int, page_size: int,
                                mean_len: int, chunk: int,
-                               pages_per_step: int) -> float:
+                               pages_per_step: int, group: int = 1) -> float:
     """Analytic v5e per-token time of one layer's chunked paged prefill.
 
     Each chunk re-streams the sequence's cached pages once (k+v int8 +
     per-token scales), so bigger chunks amortize the restream; one grid step
     covers ``pages_per_step`` pages, so bigger steps amortize issue
-    overhead. The (chunk × kv-block) f32 score tile must fit the online-
-    softmax working set in VMEM, which bounds both from above.
+    overhead. The kernel folds the ``group`` query heads of a kv head into
+    its rows, and what it keeps per row must fit the scoped VMEM limit,
+    which bounds both from above: about 32 bytes per head-dim element (the
+    f32 accumulator and q copy, the double-buffered q and output blocks,
+    the lane-padded running max and sum) and 8 per kv-block token (the f32
+    scores and probabilities). The v5e compiler asks for 17.2 MB at 4096
+    rows, hd 128 and a 32-token block, and 25.3 MB at 3584 rows, hd 64 and
+    a 1024-token block; 16 MB is the limit.
     """
     n_pages = mean_len / page_size + 0.5
     page_bytes = 2 * page_size * (head_dim + 4)   # int8 k+v + per-token scales
     hbm = kv_heads * n_pages * page_bytes + chunk * 2 * kv_heads * head_dim * 2
     steps = kv_heads * math.ceil(n_pages / pages_per_step)
-    scores = chunk * pages_per_step * page_size * 4    # f32 score tile
-    acc = chunk * head_dim * 4 * 2                     # acc + q resident
-    if scores + acc > VMEM_BYTES // 4:
+    rows = chunk * group
+    per_row = 32 * head_dim + 8 * pages_per_step * page_size
+    if rows * per_row > 0.9 * VMEM_BYTES:
         return float("inf")
     return (hbm / _HBM_BW + steps * _STEP_OVERHEAD_S) / chunk
 
 
 def get_prefill_params(kv_heads: int, head_dim: int, page_size: int,
-                       mean_len: int, *, timer: Optional[Callable] = None,
+                       mean_len: int, *, group: int = 1,
+                       timer: Optional[Callable] = None,
                        save: bool = True) -> Tuple[int, int]:
     """Cached (chunk_tokens, pages_per_step) pick for chunked paged prefill.
 
-    Lives in the same JSON cache as the GEMM blocks (its own ``pprefill|``
-    key space). ``timer(chunk, pages_per_step)`` overrides the analytic
-    scorer (tests use this).
+    ``group``: query heads per kv head. Lives in the same JSON cache as the
+    GEMM blocks (its own ``pprefill|`` key space). ``timer(chunk,
+    pages_per_step)`` overrides the analytic scorer (tests use this).
     """
-    key = (f"pprefill|kv{kv_heads}|hd{head_dim}|ps{page_size}"
+    key = (f"pprefill|kv{kv_heads}|g{group}|hd{head_dim}|ps{page_size}"
            f"|len{mean_len}|{_backend()}")
     with _lock:
         _load_disk()
@@ -413,7 +430,7 @@ def get_prefill_params(kv_heads: int, head_dim: int, page_size: int,
     if hit is not None:
         return int(hit["chunk"]), int(hit["pages_per_step"])
     score = timer or (lambda c, pp: model_paged_prefill_time_s(
-        kv_heads, head_dim, page_size, mean_len, c, pp))
+        kv_heads, head_dim, page_size, mean_len, c, pp, group))
     scores = {(c, pp): score(c, pp)
               for c in PREFILL_CHUNKS for pp in PREFILL_PAGES_PER_STEP}
     best = min(scores, key=scores.get)

@@ -37,7 +37,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.epilogue import (epilogue_needs, flush_epilogue,
                                     parse_epilogue)
 from repro.kernels.padding import pad_2d, round_up
-from repro.kernels.pltpu_compat import CompilerParams
 
 
 def _camp_gemm_kernel(*refs, stages, n_extra):
@@ -132,7 +131,7 @@ def camp_gemm_i8(
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(a_q, b_q, a_scale, b_scale, *extra)

@@ -42,7 +42,6 @@ from repro.kernels.camp_gemm import _epilogue_inputs
 from repro.kernels.camp_gemm_w4 import _even_block_k, _unpack_k_rows
 from repro.kernels.epilogue import flush_epilogue, parse_epilogue
 from repro.kernels.padding import pad_2d, round_up
-from repro.kernels.pltpu_compat import CompilerParams
 
 
 def _fused_kernel(*refs, stages, n_extra, bk, qmax, unpack_b):
@@ -124,7 +123,7 @@ def _camp_gemm_fused(x, b, b_scale, *, a_bits, w_bits, block_m, block_n,
             pltpu.VMEM((bm, 1), jnp.float32),  # per-row activation scales
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(x, b, b_scale, *extra)

@@ -27,13 +27,21 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.camp_gemm import _epilogue_inputs
 from repro.kernels.epilogue import flush_epilogue, parse_epilogue
 from repro.kernels.padding import pad_2d, round_up
-from repro.kernels.pltpu_compat import CompilerParams
+
+
+def _nibbles(packed):
+    """int8 packed bytes → (lo, hi) sign-extended int4-valued int8.
+
+    The shifts run on int32: Mosaic has no int8 shift, and the 32-bit
+    arithmetic right shift sign-extends each nibble exactly as
+    :func:`repro.core.quant.unpack_int4` does on int8."""
+    p = packed.astype(jnp.int32)
+    return ((p << 28) >> 28).astype(jnp.int8), (p >> 4).astype(jnp.int8)
 
 
 def _unpack_k_rows(packed):
     """(bk//2, bn) int8 → (bk, bn) int4-valued int8, sign-extended nibbles."""
-    lo = ((packed << 4).astype(jnp.int8) >> 4).astype(jnp.int8)
-    hi = (packed >> 4).astype(jnp.int8)
+    lo, hi = _nibbles(packed)
     # Interleave rows: out[2i] = lo[i], out[2i+1] = hi[i].
     bk2, bn = packed.shape
     return jnp.stack([lo, hi], axis=1).reshape(2 * bk2, bn)
@@ -41,8 +49,7 @@ def _unpack_k_rows(packed):
 
 def _unpack_k_cols(packed):
     """(bm, bk//2) int8 → (bm, bk): out[:, 2i] = lo, out[:, 2i+1] = hi."""
-    lo = ((packed << 4).astype(jnp.int8) >> 4).astype(jnp.int8)
-    hi = (packed >> 4).astype(jnp.int8)
+    lo, hi = _nibbles(packed)
     bm, bk2 = packed.shape
     return jnp.stack([lo, hi], axis=2).reshape(bm, 2 * bk2)
 
@@ -147,7 +154,7 @@ def camp_gemm_w4(
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(a_q, b_packed, a_scale, b_scale, *extra)
@@ -206,7 +213,7 @@ def camp_gemm_a4w4(
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(a_packed, b_packed, a_scale, b_scale, *extra)

@@ -42,6 +42,7 @@ from repro.core import autotune
 from repro.core import hybrid as _hybrid
 from repro.core.quant import unpack_int4
 from repro.kernels import ref as _ref
+from repro.kernels.backend import interpret_mode, resolve
 from repro.kernels.camp_gemm import camp_gemm_i8 as _pallas_i8
 from repro.kernels.camp_gemm_fused import camp_gemm_fused_w4a4 as _pallas_f_a4w4
 from repro.kernels.camp_gemm_fused import camp_gemm_fused_w4a8 as _pallas_f_w4
@@ -55,16 +56,8 @@ from repro.kernels.quantize import quantize_rowwise_kernel as _pallas_quant
 _VALID = ("auto", "pallas", "xla", "hybrid", "ref")
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _resolve(impl: str) -> str:
-    if impl not in _VALID:
-        raise ValueError(f"impl={impl!r} not in {_VALID}")
-    if impl == "auto":
-        return "pallas" if _on_tpu() else "xla"
-    return impl
+    return resolve(impl, _VALID)
 
 
 def _blocks(kind, m, n, k, block, *, fused=False, a_in_bytes=4):
@@ -94,7 +87,8 @@ def gemm_i8(a_q, b_q, a_scale, b_scale, *, out_dtype=jnp.float32,
         bm, bn, bk = _blocks("i8", m, n, k, block)
         return _pallas_i8(a_q, b_q, a_scale, b_scale, block_m=bm, block_n=bn,
                           block_k=bk, out_dtype=out_dtype, epilogue=epilogue,
-                          bias=bias, operand=operand, interpret=not _on_tpu())
+                          bias=bias, operand=operand,
+                          interpret=interpret_mode())
     if impl == "hybrid":
         acc = _hybrid.hybrid_matmul_i8(a_q, b_q)
     else:  # 'xla' / 'ref'
@@ -115,7 +109,7 @@ def gemm_w4(a_q, b_packed, a_scale, b_scale, *, out_dtype=jnp.float32,
         return _pallas_w4(a_q, b_packed, a_scale, b_scale, block_m=bm,
                           block_n=bn, block_k=bk, out_dtype=out_dtype,
                           epilogue=epilogue, bias=bias, operand=operand,
-                          interpret=not _on_tpu())
+                          interpret=interpret_mode())
     b_q = unpack_int4(b_packed, k)
     if impl == "hybrid":
         acc = _hybrid.hybrid_matmul_w4a8(a_q, b_q)
@@ -137,7 +131,7 @@ def gemm_a4w4(a_packed, b_packed, k, a_scale, b_scale, *,
         return _pallas_a4w4(a_packed, b_packed, a_scale, b_scale, block_m=bm,
                             block_n=bn, block_k=bk, out_dtype=out_dtype,
                             epilogue=epilogue, bias=bias, operand=operand,
-                            interpret=not _on_tpu())
+                            interpret=interpret_mode())
     a_q = unpack_int4(a_packed.T, k).T
     b_q = unpack_int4(b_packed, k)
     acc = _ref.dot_i32(a_q, b_q)
@@ -149,7 +143,8 @@ def quantize_rowwise(x, *, bits: int = 8, impl: str = "auto", block_m: int = 256
     """Fused dynamic rowwise quantization: x → (int8 q, f32 scale (M,1))."""
     impl = _resolve(impl)
     if impl == "pallas":
-        return _pallas_quant(x, bits=bits, block_m=block_m, interpret=not _on_tpu())
+        return _pallas_quant(x, bits=bits, block_m=block_m,
+                             interpret=interpret_mode())
     return _ref.quantize_rowwise_ref(x, bits)
 
 
@@ -190,7 +185,7 @@ def _gemm_fused(kind, x, b, b_scale, *, out_dtype, impl, block, epilogue,
         fn = {"i8": _pallas_f_i8, "w4": _pallas_f_w4, "a4w4": _pallas_f_a4w4}[kind]
         return fn(x, b, b_scale, block_m=bm, block_n=bn, block_k=bk,
                   out_dtype=out_dtype, epilogue=epilogue, bias=bias,
-                  operand=operand, interpret=not _on_tpu())
+                  operand=operand, interpret=interpret_mode())
     # a4w4 has no hybrid decomposition (matches the unfused dispatch, which
     # routes every non-pallas a4w4 impl through the ref dot).
     return _fused_fallback(x, b, b_scale, bias, operand,

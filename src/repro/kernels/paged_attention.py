@@ -21,8 +21,10 @@ via ``pl.when`` (padded block-table slots are never touched because the
 skip test uses lengths, not the table).
 
 ``impl='auto'`` follows the repo convention: Pallas on TPU, the XLA
-reference elsewhere. The Pallas path requires int8 pages with scales; float
-pages (used by the bf16 paged pool) route through the reference.
+reference elsewhere (:func:`repro.kernels.backend.resolve_paged`). The
+Pallas path requires int8 pages with scales: float pages (the bf16 paged
+pool) run the reference under 'auto', and an explicit ``impl='pallas'`` over
+them raises.
 
 Tensor parallelism: :func:`paged_attention_tp` shard_maps the kernel over a
 mesh's ``model`` axis with every KV-head-carrying operand split by head —
@@ -40,25 +42,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.pltpu_compat import CompilerParams
+from repro.kernels.backend import interpret_mode, resolve_paged
 
 _NEG = -1e30
-_VALID = ("auto", "pallas", "xla")
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _resolve(impl: str) -> str:
-    if impl not in _VALID:
-        raise ValueError(f"impl={impl!r} not in {_VALID}")
-    if impl == "auto":
-        return "pallas" if _on_tpu() else "xla"
-    return impl
 
 
 # ---------------------------------------------------------------------------
@@ -113,15 +101,21 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     length = lens_ref[b]
+    h = pl.program_id(1)
 
     @pl.when(j * ps < length)
     def _step():
         q = q_ref[0, 0].astype(jnp.float32)                        # (G, hd)
-        # in-register dequant: int8 page × its (token,) per-row scales
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]  # (ps, hd)
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
+        k = k_ref[0, 0].astype(jnp.float32)                        # (ps, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
+        # in-register dequant: a token's scale multiplies its score column
+        # (k) and its probability column (v), so the (1, ps) scale row
+        # never needs a lane→sublane relayout
+        ks = ks_ref[0, pl.ds(h, 1), :]                             # (1, ps)
+        vs = vs_ref[0, pl.ds(h, 1), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+                                preferred_element_type=jnp.float32)
+        s = s * (ks * scale)
         col = j * ps + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
         s = jnp.where(col < length, s, _NEG)
         m_prev = m_ref[...]
@@ -131,7 +125,7 @@ def _paged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[...] = m_new
         acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p * vs, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(j == nj - 1)
@@ -153,8 +147,10 @@ def _paged_attention_pallas(q, k_pages, v_pages, k_scale, v_scale, tables,
     def page_map(bi, hi, ji, tables_ref, lens_ref):
         return (tables_ref[bi, ji], hi, 0, 0)
 
+    # scales ride with every head of the page: a (1, 1, ps) block would
+    # break Mosaic's (8, 128)-or-full rule on its last two dims
     def scale_map(bi, hi, ji, tables_ref, lens_ref):
-        return (tables_ref[bi, ji], hi, 0)
+        return (tables_ref[bi, ji], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -164,8 +160,8 @@ def _paged_attention_pallas(q, k_pages, v_pages, k_scale, v_scale, tables,
                          lambda bi, hi, ji, t, le: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, ps, hd), page_map),
             pl.BlockSpec((1, 1, ps, hd), page_map),
-            pl.BlockSpec((1, 1, ps), scale_map),
-            pl.BlockSpec((1, 1, ps), scale_map),
+            pl.BlockSpec((1, kv, ps), scale_map),
+            pl.BlockSpec((1, kv, ps), scale_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd),
                                lambda bi, hi, ji, t, le: (bi, hi, 0, 0)),
@@ -180,7 +176,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, k_scale, v_scale, tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(tables, lengths, q, k_pages, v_pages, k_scale, v_scale)
@@ -190,12 +186,11 @@ def paged_attention(q, k_pages, v_pages, k_scale, v_scale, tables, lengths,
                     *, sm_scale: Optional[float] = None, impl: str = "auto",
                     interpret: Optional[bool] = None):
     """Paged decode attention; see :func:`paged_attention_reference` shapes."""
-    impl = _resolve(impl)
-    if impl == "pallas" and k_scale is not None:
+    if resolve_paged(impl, k_scale is not None) == "pallas":
         return _paged_attention_pallas(
             q, k_pages, v_pages, k_scale, v_scale, tables, lengths,
             sm_scale=sm_scale,
-            interpret=(not _on_tpu()) if interpret is None else interpret)
+            interpret=interpret_mode(interpret))
     return paged_attention_reference(q, k_pages, v_pages, k_scale, v_scale,
                                      tables, lengths, sm_scale=sm_scale)
 
@@ -216,18 +211,25 @@ def paged_attention_tp(q, k_pages, v_pages, k_scale, v_scale, tables,
     if kv % mesh.shape[axis]:
         raise ValueError(
             f"kv heads {kv} not divisible by {axis}={mesh.shape[axis]}")
+    fn = _paged_attention_tp_fn(mesh, axis, sm_scale, impl, interpret,
+                                k_scale is not None)
+    return fn(q, k_pages, v_pages, k_scale, v_scale, tables, lengths)
+
+
+@functools.lru_cache(maxsize=None)
+def _paged_attention_tp_fn(mesh, axis, sm_scale, impl, interpret,
+                           quantized):
+    """One jitted shard_map program per configuration (a fresh closure per
+    call would recompile on every eager decode step)."""
     head4 = P(None, axis, None, None)
-    head3 = P(None, axis, None)
-    none_spec = None if k_scale is None else head3
+    sspec = P(None, axis, None) if quantized else None
 
     def body(q_, kp, vp, ks, vs, tb, ln):
         return paged_attention(q_, kp, vp, ks, vs, tb, ln,
                                sm_scale=sm_scale, impl=impl,
                                interpret=interpret)
 
-    fn = shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
-        in_specs=(head4, head4, head4, none_spec, none_spec,
-                  P(None, None), P(None)),
-        out_specs=head4, check_rep=False)
-    return fn(q, k_pages, v_pages, k_scale, v_scale, tables, lengths)
+        in_specs=(head4, head4, head4, sspec, sspec, P(None, None), P(None)),
+        out_specs=head4, check_vma=False))

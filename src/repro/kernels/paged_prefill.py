@@ -28,8 +28,10 @@ whose ``q_start`` is wherever decode left off — usually mid-page, which the
 per-token scales make safe to resume.
 
 ``impl='auto'`` follows the repo convention: Pallas on TPU, the XLA
-reference elsewhere. The Pallas path requires int8 pages with scales; float
-pages (the bf16 paged pool) route through the reference.
+reference elsewhere (:func:`repro.kernels.backend.resolve_paged`). The
+Pallas path requires int8 pages with scales: float pages (the bf16 paged
+pool) run the reference under 'auto', and an explicit ``impl='pallas'`` over
+them raises.
 
 Tensor parallelism: :func:`paged_prefill_attention_tp` shard_maps the kernel
 over a mesh's ``model`` axis by kv head (q's leading dim, the pages' head
@@ -46,25 +48,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.pltpu_compat import CompilerParams
+from repro.kernels.backend import interpret_mode, resolve_paged
 
 _NEG = -1e30
-_VALID = ("auto", "pallas", "xla")
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _resolve(impl: str) -> str:
-    if impl not in _VALID:
-        raise ValueError(f"impl={impl!r} not in {_VALID}")
-    if impl == "auto":
-        return "pallas" if _on_tpu() else "xla"
-    return impl
 
 
 # ---------------------------------------------------------------------------
@@ -122,17 +110,23 @@ def _prefill_kernel(table_ref, q_ref, *refs, pp: int, ps: int, g: int,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
+    h = pl.program_id(0)
     q = q_ref[0].astype(jnp.float32)                            # (C*G, hd)
-    # multi-page kv block: pp pages dequantized in-register (per-token row
-    # scales) and stacked
-    k = jnp.concatenate(
-        [k_refs[i][0, 0].astype(jnp.float32) * ks_refs[i][0, 0][:, None]
-         for i in range(pp)], axis=0)                           # (pp*ps, hd)
-    v = jnp.concatenate(
-        [v_refs[i][0, 0].astype(jnp.float32) * vs_refs[i][0, 0][:, None]
-         for i in range(pp)], axis=0)
+    # multi-page kv block: pp int8 pages stacked along tokens; their
+    # per-token scales, one (1, ps) row per page, stacked along lanes
+    k = jnp.concatenate([k_refs[i][0, 0].astype(jnp.float32)
+                         for i in range(pp)], axis=0)           # (pp*ps, hd)
+    v = jnp.concatenate([v_refs[i][0, 0].astype(jnp.float32)
+                         for i in range(pp)], axis=0)
+    ks = jnp.concatenate([ks_refs[i][0, pl.ds(h, 1), :]
+                          for i in range(pp)], axis=1)          # (1, pp*ps)
+    vs = jnp.concatenate([vs_refs[i][0, pl.ds(h, 1), :]
+                          for i in range(pp)], axis=1)
+    # in-register dequant: a token's scale multiplies its score column (k)
+    # and its probability column (v)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+                            preferred_element_type=jnp.float32)
+    s = s * (ks * scale)
     rows = q.shape[0]
     col = j * pp * ps + jax.lax.broadcasted_iota(jnp.int32, (rows, pp * ps), 1)
     row_pos = q_start + jax.lax.broadcasted_iota(
@@ -145,7 +139,8 @@ def _prefill_kernel(table_ref, q_ref, *refs, pp: int, ps: int, g: int,
     l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
     m_ref[...] = m_new
     acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p * vs, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
     @pl.when(j == nj - 1)
     def _done():
@@ -177,11 +172,13 @@ def _paged_prefill_pallas(q, k_pages, v_pages, k_scale, v_scale, table, *,
     def page_map(i):
         return lambda hi, ji, t: (t[ji * pp + i], hi, 0, 0)
 
+    # scales ride with every head of the page: a (1, 1, ps) block would
+    # break Mosaic's (8, 128)-or-full rule on its last two dims
     def scale_map(i):
-        return lambda hi, ji, t: (t[ji * pp + i], hi, 0)
+        return lambda hi, ji, t: (t[ji * pp + i], 0, 0)
 
     page_spec = [pl.BlockSpec((1, 1, ps, hd), page_map(i)) for i in range(pp)]
-    scale_spec = [pl.BlockSpec((1, 1, ps), scale_map(i)) for i in range(pp)]
+    scale_spec = [pl.BlockSpec((1, kv, ps), scale_map(i)) for i in range(pp)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(kv, n_steps),
@@ -200,7 +197,7 @@ def _paged_prefill_pallas(q, k_pages, v_pages, k_scale, v_scale, table, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((kv, c * g, hd), q.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(tbl, q2, *([k_pages] * pp), *([v_pages] * pp),
@@ -215,12 +212,11 @@ def paged_prefill_attention(q, k_pages, v_pages, k_scale, v_scale, table, *,
                             interpret: Optional[bool] = None):
     """Chunked paged prefill attention; see :func:`paged_prefill_reference`
     for shapes. ``q_start`` / ``pages_per_step`` must be static."""
-    impl = _resolve(impl)
-    if impl == "pallas" and k_scale is not None:
+    if resolve_paged(impl, k_scale is not None) == "pallas":
         return _paged_prefill_pallas(
             q, k_pages, v_pages, k_scale, v_scale, table,
             q_start=q_start, pages_per_step=pages_per_step, sm_scale=sm_scale,
-            interpret=(not _on_tpu()) if interpret is None else interpret)
+            interpret=interpret_mode(interpret))
     return paged_prefill_reference(q, k_pages, v_pages, k_scale, v_scale,
                                    table, q_start=q_start, sm_scale=sm_scale)
 
@@ -243,9 +239,19 @@ def paged_prefill_attention_tp(q, k_pages, v_pages, k_scale, v_scale, table,
     if kv % mesh.shape[axis]:
         raise ValueError(
             f"kv heads {kv} not divisible by {axis}={mesh.shape[axis]}")
+    fn = _paged_prefill_tp_fn(mesh, axis, q_start, pages_per_step, sm_scale,
+                              impl, interpret, k_scale is not None)
+    return fn(q, k_pages, v_pages, k_scale, v_scale, table)
+
+
+@functools.lru_cache(maxsize=None)
+def _paged_prefill_tp_fn(mesh, axis, q_start, pages_per_step, sm_scale, impl,
+                         interpret, quantized):
+    """One jitted shard_map program per configuration (a fresh closure per
+    call would recompile on every eager prefill chunk)."""
     qspec = P(axis, None, None, None)
     head4 = P(None, axis, None, None)
-    sspec = None if k_scale is None else P(None, axis, None)
+    sspec = P(None, axis, None) if quantized else None
 
     def body(q_, kp, vp, ks, vs, tb):
         return paged_prefill_attention(
@@ -253,7 +259,7 @@ def paged_prefill_attention_tp(q, k_pages, v_pages, k_scale, v_scale, table,
             pages_per_step=pages_per_step, sm_scale=sm_scale, impl=impl,
             interpret=interpret)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(qspec, head4, head4, sspec, sspec, P(None)),
-                   out_specs=qspec, check_rep=False)
-    return fn(q, k_pages, v_pages, k_scale, v_scale, table)
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(qspec, head4, head4, sspec, sspec, P(None)),
+        out_specs=qspec, check_vma=False))

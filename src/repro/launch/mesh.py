@@ -8,25 +8,18 @@ Serving:    (data, model) with the model axis carrying tensor parallelism —
 :func:`make_serving_mesh` sizes it from the requested TP degree.
 
 Defined as functions (never module-level constants) so importing this module
-never touches jax device state. ``jax.make_mesh`` grew its ``axis_types``
-kwarg after 0.4.37; :func:`_make_mesh` feature-detects it so every mesh in
-the repo (including the 8-virtual-device CPU CI meshes) builds on either
-API generation.
+never touches jax device state.
 """
 from __future__ import annotations
-
-import inspect
 
 import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis_types where the installed jax has them."""
-    kwargs = {}
-    if ("axis_types" in inspect.signature(jax.make_mesh).parameters
-            and hasattr(jax.sharding, "AxisType")):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kwargs)
+    """jax.make_mesh with Auto axes: sharding stays with the compiler and the
+    explicit shard_map call paths, as every mesh in the repo expects."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -32,42 +32,68 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.core import autotune
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_serving_mesh
 from repro.models import init_params, quantize_params
+from repro.models.transformer import init_layer, init_top
 from repro.parallel.sharding import (effective_model_shards, make_rules,
                                      params_pspecs)
 from repro.serving.engine import generate, warm_gemm_autotune
 from repro.serving.spec_decode import SpecConfig
 
 
-def shard_params(params, mesh):
-    """device_put the params tree to its serve-rule shardings.
+def _serve_shardings(tree, mesh):
+    """NamedSharding pytree of a (shape) tree under the serve-rule table.
 
     QuantizedTensor leaves place their int payload and (1, N) scale
     separately (column-consistent specs from ``params_pspecs``).
     """
-    from jax.sharding import NamedSharding
+    specs = params_pspecs(tree, make_rules("serve"), mesh)
+    return jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), specs,
+                                  is_leaf=lambda x: isinstance(x, P))
 
-    from repro.core.quant import QuantizedTensor
 
-    rules = make_rules("serve")
-    specs = params_pspecs(params, rules, mesh)
+def shard_params(params, mesh):
+    """device_put an existing params tree to its serve-rule shardings."""
+    return jax.device_put(params, _serve_shardings(params, mesh))
 
-    def put(x, s):
-        if isinstance(x, QuantizedTensor):
-            return QuantizedTensor(
-                q=jax.device_put(x.q, NamedSharding(mesh, s.q)),
-                scale=jax.device_put(x.scale, NamedSharding(mesh, s.scale)),
-                bits=x.bits, shape=x.shape)
-        return jax.device_put(x, NamedSharding(mesh, s))
 
-    return jax.tree_util.tree_map(
-        put, params, specs,
-        is_leaf=lambda x: isinstance(x, QuantizedTensor)
-        or (hasattr(x, "shape") and not isinstance(x, dict)))
+def init_serving_params(key, cfg, qmode: str = "none", mesh=None):
+    """``init_params`` → ``quantize_params`` → placement, one layer at a time.
+
+    Every piece (embedding, final norm and head; then each block) is drawn,
+    quantized and placed in one jitted program whose outputs carry their
+    serve-rule shardings on ``mesh`` (one device without a mesh). No device
+    ever holds more than one block's float weights, so a model that only
+    fits across the mesh in its quantized form loads there: qwen2-72b at
+    w4a8 is ~35 GB of int4 weights, ~9 GB per chip at tp=4, against ~140 GB
+    for the bf16 tree. The draws are ``init_params``' own (same keys, same
+    ``init_top`` / ``init_layer``).
+    """
+    keys = jax.random.split(key, cfg.n_layers + 3)
+
+    def placed(build, example):
+        fn = jax.jit(lambda k: quantize_params(build(k), cfg, qmode))
+        if mesh is None:
+            return fn
+        out = jax.eval_shape(fn, example)
+        return jax.jit(fn, out_shardings=_serve_shardings(out, mesh))
+
+    params = placed(lambda k: init_top(k, cfg), keys)(keys)
+    fns = {}
+    layers = []
+    for i in range(cfg.n_layers):
+        kind = (cfg.mixer_of(i), cfg.ffn_of(i))
+        if kind not in fns:            # one compile per block kind
+            fns[kind] = placed(lambda k, kind=kind: init_layer(k, cfg, *kind),
+                               keys[i + 2])
+        layers.append(fns[kind](keys[i + 2]))
+    params["layers"] = layers
+    return params
 
 
 def main():
@@ -97,13 +123,15 @@ def main():
                          "(always built with --reduced shapes)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced, qmode=args.qmode)
     key = jax.random.PRNGKey(args.seed)
-    params = init_params(key, cfg)
-    if args.qmode != "none":
-        t0 = time.time()
-        params = quantize_params(params, cfg, args.qmode)
-        print(f"[serve] PTQ to {args.qmode} in {time.time()-t0:.2f}s")
+    mesh = make_serving_mesh(args.tp) if args.tp > 1 else None
+    t0 = time.time()
+    params = init_serving_params(key, cfg, args.qmode, mesh)
+    jax.block_until_ready(params)
+    print(f"[serve] weights ({args.qmode}) built and placed in "
+          f"{time.time()-t0:.2f}s")
 
     spec = None
     if args.spec_method != "off":
@@ -126,10 +154,7 @@ def main():
         print(f"[serve] speculative decoding: {args.spec_method}, "
               f"gamma={gamma}")
 
-    mesh = None
-    if args.tp > 1:
-        mesh = make_serving_mesh(args.tp)
-        params = shard_params(params, mesh)
+    if mesh is not None:
         tp_eff = effective_model_shards(mesh, cfg.n_kv_heads)
         sharded = tp_eff if tp_eff > 1 else "replicated"
         print(f"[serve] mesh {dict(mesh.shape)}; kv-head sharding: {sharded}")
@@ -144,7 +169,7 @@ def main():
     t0 = time.time()
     if spec is None:
         toks = generate(params, cfg, prompt, steps=args.steps, key=key,
-                        sample=args.sample, mesh=mesh,
+                        sample=args.sample, kv_dtype="int8", mesh=mesh,
                         tp_int8_reduce=args.tp_int8_reduce)
     else:
         # drive the engine directly so the acceptance stats are reportable

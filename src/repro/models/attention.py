@@ -39,8 +39,9 @@ from repro.kernels.paged_attention import paged_attention, paged_attention_tp
 from repro.kernels.paged_prefill import (paged_prefill_attention,
                                          paged_prefill_attention_tp)
 from repro.models.config import ModelConfig
-from repro.models.modules import (apply_rope, linear, rms_norm, rope_freqs,
-                                  row_parallel_linear, tp_shardable)
+from repro.models.modules import (apply_rope, column_linear, linear,
+                                  rms_norm, rope_freqs, row_parallel_linear,
+                                  tp_shardable)
 from repro.parallel.sharding import (effective_model_shards, logical,
                                      serve_tp)
 from repro.serving.kv_cache import (DEFAULT_PAGE_SIZE, DenseKVCache,
@@ -112,9 +113,12 @@ def attention(p: dict, cfg: ModelConfig, x: jax.Array, positions: jax.Array,
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     g = h // kv
 
-    q = linear(x, p["wq"], p.get("wq_bias"), qmode=qmode).reshape(b, s, h, hd)
-    k = linear(x, p["wk"], p.get("wk_bias"), qmode=qmode).reshape(b, s, kv, hd)
-    v = linear(x, p["wv"], p.get("wv_bias"), qmode=qmode).reshape(b, s, kv, hd)
+    q = column_linear(x, p["wq"], p.get("wq_bias"),
+                      qmode=qmode).reshape(b, s, h, hd)
+    k = column_linear(x, p["wk"], p.get("wk_bias"),
+                      qmode=qmode).reshape(b, s, kv, hd)
+    v = column_linear(x, p["wv"], p.get("wv_bias"),
+                      qmode=qmode).reshape(b, s, kv, hd)
 
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)

@@ -12,11 +12,11 @@ divides the model axis; otherwise they fall back to the replicated path.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.camp import camp_matmul, weight_bits
@@ -122,34 +122,86 @@ def row_parallel_linear(x: jax.Array, w, *, mesh, axis: str = "model",
     """
     if quantized_reduce is None:
         quantized_reduce = _tp_int8_reduce()
-    xspec = P(*((None,) * (x.ndim - 1) + (axis,)))
-    yspec = P(*((None,) * x.ndim))
+    if isinstance(w, QuantizedTensor):
+        fn = _row_parallel_fn(mesh, axis, x.ndim, qmode, impl,
+                              quantized_reduce, w.bits, w.shape[1])
+        return fn(x, w.q, w.scale).astype(x.dtype)
+    fn = _row_parallel_fn(mesh, axis, x.ndim, qmode, impl, quantized_reduce,
+                          None, None)
+    return fn(x, w).astype(x.dtype)
+
+
+# The shard_map programs below are built once per static configuration and
+# jitted: a fresh shard_map closure per call would be retraced and
+# recompiled on every call of an eagerly driven engine step.
+@functools.lru_cache(maxsize=None)
+def _row_parallel_fn(mesh, axis, ndim, qmode, impl, quantized_reduce, bits,
+                     n):
+    xspec = P(*((None,) * (ndim - 1) + (axis,)))
+    yspec = P(*((None,) * ndim))
 
     def reduce(y):
         y = y.astype(jnp.float32)
         return quantized_psum(y, axis) if quantized_reduce \
             else jax.lax.psum(y, axis)
 
-    if isinstance(w, QuantizedTensor):
-        n = w.shape[1]
-        bits = w.bits
-
+    if bits is None:
+        def body(x_l, w_l):
+            return reduce(jnp.matmul(x_l, w_l.astype(x_l.dtype)))
+        in_specs = (xspec, P(axis, None))
+    else:
         def body(x_l, wq_l, ws_l):
             w_l = QuantizedTensor(q=wq_l, scale=ws_l, bits=bits,
                                   shape=(x_l.shape[-1], n))
             return reduce(linear(x_l, w_l, qmode=qmode, impl=impl))
+        in_specs = (xspec, P(axis, None), P(None, None))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=yspec, check_vma=False))
 
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(xspec, P(axis, None), P(None, None)),
-                       out_specs=yspec, check_rep=False)
-        return fn(x, w.q, w.scale).astype(x.dtype)
 
-    def body(x_l, w_l):
-        return reduce(jnp.matmul(x_l, w_l.astype(x_l.dtype)))
+def column_linear(x: jax.Array, w, bias: Optional[jax.Array] = None, *,
+                  qmode: str = "none", epilogue: Optional[str] = None,
+                  operand: Optional[jax.Array] = None) -> jax.Array:
+    """``linear`` for a column-parallel weight (q/k/v, gate/up, lm head).
 
-    fn = shard_map(body, mesh=mesh, in_specs=(xspec, P(axis, None)),
-                   out_specs=yspec, check_rep=False)
-    return fn(x, w).astype(x.dtype)
+    Under an active ``mode='serve'`` mesh context a quantized weight runs
+    its CAMP kernel per device through ``shard_map``: the compiler cannot
+    partition a Mosaic kernel, so a kernel fed mesh-placed operands is
+    refused on a TPU. Each device multiplies the (replicated) activations by
+    its column shard of the weight — the activation quantization sees whole
+    rows, so the result equals the single-device GEMM — and the output
+    stays sharded along N, with no collective. A weight whose N the model
+    axis does not divide (replicated by the serve rules) runs whole on
+    every device. Float weights stay with XLA's partitioner.
+    """
+    mesh, tp = serve_tp()
+    if mesh is None or not isinstance(w, QuantizedTensor):
+        return linear(x, w, bias, qmode=qmode, epilogue=epilogue,
+                      operand=operand)
+    axis = "model" if w.shape[1] % tp == 0 else None
+    fn = _column_parallel_fn(mesh, axis, x.ndim, qmode, epilogue, w.bits,
+                             w.shape[0], w.shape[1] // (tp if axis else 1),
+                             bias is not None, operand is not None)
+    return fn(x, w.q, w.scale, bias, operand)
+
+
+@functools.lru_cache(maxsize=None)
+def _column_parallel_fn(mesh, axis, ndim, qmode, epilogue, bits, k, n_l,
+                        has_bias, has_operand):
+    rep = P(*((None,) * ndim))
+    col = P(*((None,) * (ndim - 1) + (axis,)))
+
+    def body(x_l, wq_l, ws_l, b_l, o_l):
+        w_l = QuantizedTensor(q=wq_l, scale=ws_l, bits=bits, shape=(k, n_l))
+        return linear(x_l, w_l, b_l, qmode=qmode, epilogue=epilogue,
+                      operand=o_l)
+
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(rep, P(None, axis), P(None, axis),
+                  P(axis) if has_bias else None,
+                  col if has_operand else None),
+        out_specs=col, check_vma=False))
 
 
 def rope_freqs(positions: jax.Array, head_dim: int, theta: float) -> tuple:
@@ -176,12 +228,12 @@ def gated_mlp(x: jax.Array, p: dict, *, qmode: str = "none") -> jax.Array:
     Three fused kernel calls, zero standalone elementwise ops: the gate
     projection applies SiLU in its flush, the up projection multiplies by the
     activated gate in *its* flush, and the down projection is plain. Under a
-    serve-mode mesh the gate/up projections are column-parallel (weights
-    d_ff-sharded via the logical rules) and the down projection runs the
-    explicit row-parallel shard_map path — one all-reduce per MLP.
+    serve-mode mesh the gate/up projections run the column-parallel
+    shard_map path (weights d_ff-sharded via the logical rules) and the down
+    projection the row-parallel one — one all-reduce per MLP.
     """
-    g = linear(x, p["w_gate"], qmode=qmode, epilogue="silu")
-    h = linear(x, p["w_up"], qmode=qmode, epilogue="mul", operand=g)
+    g = column_linear(x, p["w_gate"], qmode=qmode, epilogue="silu")
+    h = column_linear(x, p["w_up"], qmode=qmode, epilogue="mul", operand=g)
     h = logical(h, "batch", "seq", "d_ff")
     mesh, tp = serve_tp()
     if mesh is not None and tp_shardable(p["w_down"], tp):

@@ -22,7 +22,8 @@ from repro.models import moe as moe_mod
 from repro.models import rwkv as rwkv_mod
 from repro.models import ssm as ssm_mod
 from repro.models.config import ModelConfig
-from repro.models.modules import gated_mlp, linear, rms_norm, softmax_xent
+from repro.models.modules import (column_linear, gated_mlp, rms_norm,
+                                  softmax_xent)
 from repro.parallel.sharding import logical
 
 MOE_AUX_COEF = 0.01
@@ -33,45 +34,55 @@ def _dtype(cfg: ModelConfig):
 
 
 def init_params(key, cfg: ModelConfig) -> dict:
-    dt = _dtype(cfg)
     keys = jax.random.split(key, cfg.n_layers + 3)
+    params = init_top(keys, cfg)
+    params["layers"] = [
+        init_layer(keys[i + 2], cfg, cfg.mixer_of(i), cfg.ffn_of(i))
+        for i in range(cfg.n_layers)]
+    return params
+
+
+def init_top(keys, cfg: ModelConfig) -> dict:
+    """Embedding, final norm and (untied) head from ``init_params``' keys."""
+    dt = _dtype(cfg)
     params: dict = {
         "embedding": (jax.random.normal(keys[0], (cfg.vocab_size, cfg.d_model))
                       * 0.02).astype(dt),
         "final_norm": jnp.ones((cfg.d_model,), dt),
-        "layers": [],
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(
             keys[1], (cfg.d_model, cfg.vocab_size)) * 0.02).astype(dt)
-    for i in range(cfg.n_layers):
-        k1, k2 = jax.random.split(keys[i + 2])
-        layer: dict = {"ln1": jnp.ones((cfg.d_model,), dt),
-                       "ln2": jnp.ones((cfg.d_model,), dt)}
-        mixer = cfg.mixer_of(i)
-        if mixer == "attn":
-            layer["attn"] = attn_mod.init_attention(k1, cfg, dt)
-        elif mixer == "mamba":
-            layer["mamba"] = ssm_mod.init_mamba(k1, cfg, dt)
-        elif mixer == "rwkv":
-            layer["rwkv_tm"] = rwkv_mod.init_rwkv_time_mix(k1, cfg, dt)
-        else:
-            raise ValueError(mixer)
-        ffn = cfg.ffn_of(i)
-        if ffn == "dense":
-            k3 = jax.random.split(k2, 3)
-            d, f = cfg.d_model, cfg.d_ff
-            layer["mlp"] = {
-                "w_gate": (jax.random.normal(k3[0], (d, f)) * d ** -0.5).astype(dt),
-                "w_up": (jax.random.normal(k3[1], (d, f)) * d ** -0.5).astype(dt),
-                "w_down": (jax.random.normal(k3[2], (f, d)) * f ** -0.5).astype(dt),
-            }
-        elif ffn == "moe":
-            layer["moe"] = moe_mod.init_moe(k2, cfg, dt)
-        elif ffn == "rwkv_cmix":
-            layer["rwkv_cm"] = rwkv_mod.init_rwkv_channel_mix(k2, cfg, dt)
-        params["layers"].append(layer)
     return params
+
+
+def init_layer(key, cfg: ModelConfig, mixer: str, ffn: str) -> dict:
+    """One block's parameters (``init_params`` passes layer i its key)."""
+    dt = _dtype(cfg)
+    k1, k2 = jax.random.split(key)
+    layer: dict = {"ln1": jnp.ones((cfg.d_model,), dt),
+                   "ln2": jnp.ones((cfg.d_model,), dt)}
+    if mixer == "attn":
+        layer["attn"] = attn_mod.init_attention(k1, cfg, dt)
+    elif mixer == "mamba":
+        layer["mamba"] = ssm_mod.init_mamba(k1, cfg, dt)
+    elif mixer == "rwkv":
+        layer["rwkv_tm"] = rwkv_mod.init_rwkv_time_mix(k1, cfg, dt)
+    else:
+        raise ValueError(mixer)
+    if ffn == "dense":
+        k3 = jax.random.split(k2, 3)
+        d, f = cfg.d_model, cfg.d_ff
+        layer["mlp"] = {
+            "w_gate": (jax.random.normal(k3[0], (d, f)) * d ** -0.5).astype(dt),
+            "w_up": (jax.random.normal(k3[1], (d, f)) * d ** -0.5).astype(dt),
+            "w_down": (jax.random.normal(k3[2], (f, d)) * f ** -0.5).astype(dt),
+        }
+    elif ffn == "moe":
+        layer["moe"] = moe_mod.init_moe(k2, cfg, dt)
+    elif ffn == "rwkv_cmix":
+        layer["rwkv_cm"] = rwkv_mod.init_rwkv_channel_mix(k2, cfg, dt)
+    return layer
 
 
 def _block(lp: dict, cfg: ModelConfig, i: int, h: jax.Array,
@@ -165,7 +176,8 @@ def forward(params: dict, cfg: ModelConfig, inputs: jax.Array,
     if last_logits_only:
         h = h[:, -1:]
     head = params["embedding"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = linear(h, head, qmode="none" if cfg.tie_embeddings else qmode)
+    logits = column_linear(h, head,
+                           qmode="none" if cfg.tie_embeddings else qmode)
     # batch_out drops the model axis from the batch so 'vocab' can carry it:
     # vocab-sharded logits keep the (B,S,V) xent buffers and the lm_head/
     # embedding f32 gradients sharded (biggest single-param grad in training).

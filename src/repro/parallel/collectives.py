@@ -28,7 +28,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -59,9 +58,9 @@ def ring_collective_matmul(x: jax.Array, w: jax.Array, mesh: Mesh,
                 cur = jax.lax.ppermute(cur, axis, perm)
         return y
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P(axis, None), P(None, axis)),
-                   out_specs=P(None, axis))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(axis, None), P(None, axis)),
+                       out_specs=P(None, axis), check_vma=False)
     return fn(x, w)
 
 
@@ -108,6 +107,6 @@ def int8_allreduce_mean(g: jax.Array, mesh: Mesh, axis: str = "data"):
         total = jax.lax.psum(q, axis)
         return (total.astype(jnp.float32) * scale / p).astype(blk.dtype)
 
-    fn = shard_map(body, mesh=mesh, in_specs=P(*(None,) * g.ndim),
-                   out_specs=P(*(None,) * g.ndim))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P(*(None,) * g.ndim),
+                       out_specs=P(*(None,) * g.ndim), check_vma=False)
     return fn(g)

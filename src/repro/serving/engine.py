@@ -155,6 +155,19 @@ def warm_gemm_autotune(cfg: ModelConfig, *, batch_sizes=(1, 8, 32),
     return out
 
 
+def paged_shape(cfg: ModelConfig, page_size: Optional[int] = None):
+    """(page size, prefill chunk, pages per prefill grid step) the engine
+    serves ``cfg`` with: from the persistent autotune cache (the analytic
+    v5e model off-TPU), unless the caller pins the page size."""
+    mean_len = max(cfg.max_seq_len // 2, 128)
+    ps = page_size or autotune.get_page_size(cfg.n_kv_heads, cfg.hd,
+                                             mean_len=mean_len)
+    chunk, pp = autotune.get_prefill_params(
+        cfg.n_kv_heads, cfg.hd, ps, mean_len=mean_len,
+        group=cfg.n_heads // cfg.n_kv_heads)
+    return ps, chunk, pp
+
+
 def build_prefill_step(cfg: ModelConfig, *, max_len: Optional[int] = None):
     """(params, inputs, caches) → (last_token_logits, caches)."""
 
@@ -305,13 +318,7 @@ class ContinuousBatchingEngine:
         # sharding degree the pool/kernels actually get (replicated fallback
         # for head counts the model axis doesn't divide)
         self.tp = effective_model_shards(mesh, cfg.n_kv_heads)
-        # page size / prefill chunking come from the persistent autotune
-        # cache (analytic v5e model off-TPU) unless pinned by the caller
-        mean_len = max(cfg.max_seq_len // 2, 128)
-        ps = page_size or autotune.get_page_size(
-            cfg.n_kv_heads, cfg.hd, mean_len=mean_len)
-        chunk, pp = autotune.get_prefill_params(
-            cfg.n_kv_heads, cfg.hd, ps, mean_len=mean_len)
+        ps, chunk, pp = paged_shape(cfg, page_size)
         chunk = prefill_chunk or chunk
         # non-final chunks must cover whole pages so a partial page is
         # quantized exactly once (by the final chunk)
@@ -546,6 +553,27 @@ class ContinuousBatchingEngine:
             self.spec_gamma = autotune.get_spec_gamma(
                 self.spec_totals.acceptance_rate,
                 draft_cost=self.drafter.cost_ratio)
+
+    def prefill_logits(self, tokens) -> np.ndarray:
+        """(V,) f32 last-position logits of ``tokens`` prefilled through the
+        engine's own chunked paged path (same chunks, pool, kernels and
+        mesh), without admitting a request; the pages are released after.
+        The served path's first-step logits, for parity checks."""
+        tokens = list(np.asarray(tokens).reshape(-1))
+        seq_id = -1 - self._next_id      # never a submitted request's id
+        self.pool.reserve(seq_id, len(tokens))
+        pos = 0
+        with self._mesh_scope():
+            while pos < len(tokens):
+                c = min(self.chunk_tokens, len(tokens) - pos)
+                out = sd.paged_chunk_forward(
+                    self.params, self.cfg, self.pool, seq_id,
+                    tokens[pos:pos + c], pos,
+                    pages_per_step=self.pages_per_step,
+                    logits="last" if pos + c == len(tokens) else "none")
+                pos += c
+        self.pool.release(seq_id)
+        return np.asarray(out[0, -1], np.float32)
 
     def spec_summary(self) -> Dict:
         """Aggregate + per-request draft/verify stats (finished AND

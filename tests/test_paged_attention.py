@@ -90,3 +90,24 @@ def test_reference_matches_dense_grouped_attn():
     want = jnp.concatenate(outs, axis=0)               # (B, KV, G, hd)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_explicit_pallas_over_float_pages_raises(kernel):
+    """Float pages have no kernel: 'auto' runs the reference, and an
+    explicit impl='pallas' is refused instead of falling back."""
+    from repro.kernels.paged_prefill import paged_prefill_attention
+    kp = vp = jnp.zeros((4, 1, 8, 16), jnp.float32)
+    table = jnp.zeros((2,), jnp.int32)
+    if kernel == "decode":
+        q = jnp.zeros((1, 1, 2, 16), jnp.float32)
+        call = lambda impl: paged_attention(  # noqa: E731
+            q, kp, vp, None, None, table[None], jnp.ones((1,), jnp.int32),
+            impl=impl)
+    else:
+        q = jnp.zeros((1, 3, 2, 16), jnp.float32)
+        call = lambda impl: paged_prefill_attention(  # noqa: E731
+            q, kp, vp, None, None, table, q_start=0, impl=impl)
+    assert call("auto").shape == q.shape
+    with pytest.raises(ValueError, match="int8 pages"):
+        call("pallas")

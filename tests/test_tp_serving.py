@@ -3,7 +3,7 @@ autotune-warmup dedupe, and the 8-virtual-device parity suite.
 
 The multi-device checks (sharded vs single-device decode/prefill logits,
 engine page accounting, indivisible-head fallback) live in
-``tests/tp_parity_check.py`` and run in a subprocess with
+``tests/tp_parity_check.py`` and run one subprocess per check with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` — the repo
 convention for device-count overrides (they must not leak into the pytest
 session). The in-process tests below need no mesh devices at all.
@@ -12,6 +12,7 @@ import os
 import subprocess
 import sys
 
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core import autotune
@@ -111,15 +112,24 @@ def test_engine_without_mesh_is_single_device():
     assert eng.tp == 1 and eng.mesh is None and not eng.pool.sharded
 
 
-def test_tp_parity_subprocess():
-    """Sharded decode + prefill logits parity, engine page accounting and
-    the indivisible-head fallback, on an 8-virtual-device CPU mesh."""
+# check name in tests/tp_parity_check.py → the markers it prints
+TP_CHECKS = {"prefill_decode": ("PREFILL_OK", "DECODE_OK"),
+             "engine": ("ENGINE_OK",), "indivisible": ("INDIV_OK",),
+             "quantized": ("QUANT_OK",), "spec": ("SPEC_OK",)}
+
+
+@pytest.mark.parametrize("check", list(TP_CHECKS))
+def test_tp_parity_subprocess(check):
+    """One 8-virtual-device parity check per case (sharded decode/prefill
+    logits, engine page accounting, the indivisible-head fallback, the
+    quantized TP GEMMs, speculative decoding), each in its own subprocess
+    with its own time limit."""
     script = os.path.join(os.path.dirname(__file__), "tp_parity_check.py")
     env = {**os.environ,
            "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
-    res = subprocess.run([sys.executable, script], capture_output=True,
-                         text=True, timeout=520, env=env)
-    for marker in ("PREFILL_OK", "DECODE_OK", "ENGINE_OK", "INDIV_OK",
-                   "QUANT_OK", "SPEC_OK", "TP_PARITY_OK"):
+    res = subprocess.run([sys.executable, script, check],
+                         capture_output=True, text=True, timeout=400,
+                         env=env)
+    for marker in TP_CHECKS[check] + ("TP_PARITY_OK",):
         assert marker in res.stdout, \
             (marker, res.stdout[-1000:], res.stderr[-3000:])

@@ -1,9 +1,12 @@
 """Sharded-vs-single-device serving parity driver (run as a script).
 
-Spawned by ``tests/test_tp_serving.py`` (and by the CI sharded-serving job)
-with ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the mesh has
+    python tests/tp_parity_check.py [check ...]     # default: every check
+
+Spawned by ``tests/test_tp_serving.py`` (one subprocess per check) and by
+the CI sharded-serving job with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the mesh has
 8 virtual CPU devices; the device-count override must never leak into the
-pytest session, hence the subprocess. Checks, in order:
+pytest session, hence the subprocess. Checks (names in ``CHECKS``):
 
 * PREFILL_OK — every chunk's last-position logits of a chunked paged
   prefill match between a head-sharded pool under the serve mesh context
@@ -202,7 +205,6 @@ def check_indivisible():
 
 
 def check_quantized():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.camp import prepare_weight
@@ -213,9 +215,9 @@ def check_quantized():
     rng = np.random.default_rng(5)
     # quantized_psum: exact integer sum, one shared quantization step
     x = jnp.asarray(rng.standard_normal((16, 32)), jnp.float32)
-    f = shard_map(lambda a: quantized_psum(a, "model"), mesh=MESH,
-                  in_specs=P(None, "model"), out_specs=P(None, None),
-                  check_rep=False)
+    f = jax.shard_map(lambda a: quantized_psum(a, "model"), mesh=MESH,
+                      in_specs=P(None, "model"), out_specs=P(None, None),
+                      check_vma=False)
     want = x.reshape(16, TP, 32 // TP).transpose(1, 0, 2).sum(0)
     step = float(jnp.max(jnp.abs(x))) / 127.0
     assert np.abs(np.asarray(f(x)) - np.asarray(want)).max() <= TP * step
@@ -292,11 +294,13 @@ def check_spec():
     print("SPEC_OK")
 
 
+CHECKS = {"prefill_decode": check_prefill_decode, "engine": check_engine,
+          "indivisible": check_indivisible, "quantized": check_quantized,
+          "spec": check_spec}
+
+
 if __name__ == "__main__":
     assert len(jax.devices()) >= 8, "needs 8 virtual devices (XLA_FLAGS)"
-    check_prefill_decode()
-    check_engine()
-    check_indivisible()
-    check_quantized()
-    check_spec()
+    for name in sys.argv[1:] or CHECKS:
+        CHECKS[name]()
     print("TP_PARITY_OK")
