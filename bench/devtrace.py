@@ -1,0 +1,142 @@
+"""The profiler trace of a window, reduced to device time by program and
+to idle gaps by what the host was doing.
+
+The run starts JAX's profiler before the window and stops it after; the
+window itself is a host span named ``bench.window``, and each engine step a
+span ``bench.step``. From the ``.xplane.pb`` file this module reads:
+
+- busy time: the union of the intervals of the device's ``XLA Ops`` events
+  inside the window, averaged over the chips that have events;
+- time by program: the ``XLA Modules`` events, summed by program name with
+  JAX's ``jit_`` prefix and the trailing ``(id)`` taken off — a Pallas
+  kernel wrapped in ``jax.jit`` is its own program, so this is kernel time;
+- the longest idle gaps, each named by the innermost host span that
+  covers its middle.
+"""
+from __future__ import annotations
+
+import dataclasses
+import glob
+import os
+import re
+import shutil
+from typing import Dict, List, Optional, Tuple
+
+WINDOW_SPAN = "bench.window"
+_ID = re.compile(r"\(\d+\)$")
+
+
+@dataclasses.dataclass
+class Summary:
+    window_s: float
+    busy_s: float
+    programs: Dict[str, float]           # program name -> device seconds
+    top_ops: List[list]                  # [[program, seconds]], longest first
+    idle_gaps: List[list]                # [[host span, seconds]], longest first
+    chips: int
+
+
+def program_name(event_name: str) -> str:
+    name = _ID.sub("", event_name.strip())
+    return name[4:] if name.startswith("jit_") else name
+
+
+def start(bench_dir: str) -> str:
+    """Start the profiler into a fixed directory in the checkout."""
+    import jax
+    d = os.path.join(os.path.dirname(bench_dir), ".bench_cache", "trace")
+    shutil.rmtree(d, ignore_errors=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(d, profiler_options=opts)
+    return d
+
+
+def stop_and_reduce(d: str) -> Optional[Summary]:
+    import jax
+    jax.profiler.stop_trace()
+    files = sorted(glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                             recursive=True))
+    if not files:
+        return None
+    from jax.profiler import ProfileData
+    return reduce(ProfileData.from_file(files[-1]))
+
+
+def _union(intervals: List[Tuple[float, float]]) -> List[Tuple[float, float]]:
+    out: List[List[float]] = []
+    for a, b in sorted(intervals):
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    return [(a, b) for a, b in out]
+
+
+def _clip(a, b, lo, hi):
+    return max(a, lo), min(b, hi)
+
+
+def reduce(profile) -> Optional[Summary]:
+    """Reduce a ``ProfileData`` (or anything with the same ``planes`` /
+    ``lines`` / ``events`` shape) to a :class:`Summary`."""
+    window = None
+    host_spans: List[Tuple[float, float, str]] = []
+    devices = []
+    for plane in profile.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    a, b = e.start_ns, e.start_ns + e.duration_ns
+                    if e.name == WINDOW_SPAN:
+                        window = (a, b)
+                    else:
+                        host_spans.append((a, b, e.name))
+        elif plane.name.startswith("/device:") and "TPU" in plane.name:
+            ops, mods = [], []
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    ops = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                           for e in line.events]
+                elif line.name == "XLA Modules":
+                    mods = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                            for e in line.events]
+            if ops or mods:
+                devices.append((ops or mods, mods))
+    if window is None or not devices:
+        return None
+    lo, hi = window
+    busy_total = 0.0
+    programs: Dict[str, float] = {}
+    gaps: List[Tuple[float, float]] = []
+    for ops, mods in devices:
+        clipped = [_clip(a, b, lo, hi) for a, b, _ in ops]
+        merged = _union([(a, b) for a, b in clipped if b > a])
+        busy_total += sum(b - a for a, b in merged)
+        prev = lo
+        for a, b in merged:
+            if a > prev:
+                gaps.append((prev, a))
+            prev = b
+        if hi > prev:
+            gaps.append((prev, hi))
+        for a, b, name in mods:
+            a, b = _clip(a, b, lo, hi)
+            if b > a:
+                key = program_name(name)
+                programs[key] = programs.get(key, 0.0) + (b - a) * 1e-9
+    n = len(devices)
+    gaps.sort(key=lambda g: g[0] - g[1])
+    host_spans.sort(key=lambda s: s[1] - s[0])       # innermost first
+    idle = []
+    for a, b in gaps[:10]:
+        mid = (a + b) / 2
+        label = next((name for s, e, name in host_spans if s <= mid <= e),
+                     "no host span")
+        idle.append([label, (b - a) * 1e-9])
+    top = sorted(([k, v / n] for k, v in programs.items()),
+                 key=lambda kv: -kv[1])
+    return Summary(window_s=(hi - lo) * 1e-9, busy_s=busy_total * 1e-9 / n,
+                   programs={k: v / n for k, v in programs.items()},
+                   top_ops=top, idle_gaps=idle, chips=n)
