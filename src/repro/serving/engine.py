@@ -62,6 +62,7 @@ from repro.parallel.sharding import (effective_model_shards, make_rules,
                                      mesh_context)
 from repro.serving import kv_cache as kvc
 from repro.serving import spec_decode as sd
+from repro.serving import tracing
 
 
 def init_serve_caches(cfg: ModelConfig, batch: int, max_len: int,
@@ -288,6 +289,14 @@ class ContinuousBatchingEngine:
     replicated (outside the mesh scope); only verification is
     tensor-parallel. ``gamma='auto'`` re-picks the window from the
     measured acceptance rate through the autotune cache's ``spec|`` keys.
+
+    **Tracing.** ``tracer=`` (:class:`repro.serving.tracing.Tracer`;
+    default :data:`repro.serving.tracing.DEFAULT`) gets a span at each
+    host-side phase of a step: ``serve.step``, ``serve.admit`` per admitted
+    request, ``serve.prefill_chunk``, ``serve.decode``, and inside the two
+    lanes ``serve.forward`` (the forward and its writeback) and
+    ``serve.sample`` (where the host waits for the sampled tokens). The
+    speculative lane has none yet.
     """
 
     SPEC_RETUNE_EVERY = 16               # spec steps between auto-γ re-picks
@@ -302,13 +311,15 @@ class ContinuousBatchingEngine:
                  key: Optional[jax.Array] = None,
                  mesh=None, rules=None, tp_int8_reduce: bool = False,
                  retain_pages: Optional[int] = None,
-                 spec: Optional[sd.SpecConfig] = None):
+                 spec: Optional[sd.SpecConfig] = None,
+                 tracer: Optional[tracing.Tracer] = None):
         mixers = {cfg.mixer_of(i) for i in range(cfg.n_layers)}
         if mixers != {"attn"}:
             raise ValueError(
                 f"continuous batching requires attention mixers, got {mixers}"
                 " (hybrid/recurrent models use the dense serving path)")
         self.params, self.cfg = params, cfg
+        self.tracer = tracer if tracer is not None else tracing.DEFAULT
         self.sample, self.temperature = sample, temperature
         self.key = jax.random.PRNGKey(0) if key is None else key
         self.mesh = mesh
@@ -413,8 +424,11 @@ class ContinuousBatchingEngine:
                         f"pool has {self.pool.num_pages} total")
                 break
             self.waiting.popleft()
-            nxt.pos = self.pool.reserve(nxt.seq_id, nxt.reserve_tokens,
-                                        prompt=nxt.prompt_tokens)
+            with self.tracer.span("serve.admit", seq_id=nxt.seq_id,
+                                  prompt_tokens=len(nxt.prompt_tokens)):
+                nxt.pos = self.pool.reserve(nxt.seq_id, nxt.reserve_tokens,
+                                            prompt=nxt.prompt_tokens)
+                self.tracer.note(matched=nxt.pos)
             self.prefilling.append(nxt)
 
     def _run_prefill_chunk(self, req: Request, chunk: int,
@@ -423,11 +437,12 @@ class ContinuousBatchingEngine:
         the pool's pages (no dense staging slab). Mid-prompt chunks skip
         the vocabulary head entirely."""
         t0 = req.pos
-        logits = sd.paged_chunk_forward(
-            self.params, self.cfg, self.pool, req.seq_id,
-            req.prompt[t0:t0 + chunk], t0,
-            pages_per_step=self.pages_per_step,
-            logits="last" if need_logits else "none")
+        with self.tracer.span("serve.forward"):
+            logits = sd.paged_chunk_forward(
+                self.params, self.cfg, self.pool, req.seq_id,
+                req.prompt[t0:t0 + chunk], t0,
+                pages_per_step=self.pages_per_step,
+                logits="last" if need_logits else "none")
         req.pos = t0 + chunk
         return logits
 
@@ -448,14 +463,19 @@ class ContinuousBatchingEngine:
                 chunk -= chunk % self.pool.page_size
                 if chunk == 0:
                     break        # leftover budget smaller than one page
-            logits = self._run_prefill_chunk(req, chunk,
-                                             need_logits=(req.pos + chunk == s))
+            last = req.pos + chunk == s
+            with self.tracer.span("serve.prefill_chunk", seq_id=req.seq_id,
+                                  q_start=req.pos, tokens=chunk, last=last):
+                logits = self._run_prefill_chunk(req, chunk, need_logits=last)
+                if last:
+                    self.prefilling.popleft()
+                    self.pool.register_prefix(req.seq_id, req.prompt_tokens)
+                    with self.tracer.span("serve.sample", rows=1):
+                        req.tokens.append(int(self._sample_tokens(
+                            logits[:, -1], [req])[0]))
             budget -= chunk
-            if req.pos < s:
+            if not last:
                 continue
-            self.prefilling.popleft()
-            self.pool.register_prefix(req.seq_id, req.prompt_tokens)
-            req.tokens.append(int(self._sample_tokens(logits[:, -1], [req])[0]))
             if len(req.tokens) >= req.max_new_tokens:
                 self._finish(req)
             else:
@@ -466,21 +486,27 @@ class ContinuousBatchingEngine:
         reqs = list(self.active)
         seq_ids = [r.seq_id for r in reqs]
         ps = self.pool.page_size
-        for r in reqs:
-            # COW barrier: the page this append touches must be exclusive
-            self.pool.ensure_writable(r.seq_id, self.pool.lens[r.seq_id] // ps)
-        tokens = jnp.asarray([[r.tokens[-1]] for r in reqs], jnp.int32)
-        tables, lengths = self.pool.batch_tables(seq_ids)
-        caches = [{"attn": self.pool.layer_cache(i, tables, lengths)}
-                  for i in range(self.cfg.n_layers)]
-        logits, new_caches, _ = forward(self.params, self.cfg, tokens,
-                                        positions=lengths[:, None],
-                                        caches=caches)
-        for i, layer in enumerate(new_caches):
-            self.pool.writeback(i, layer["attn"])
-        for r in reqs:
-            self.pool.lens[r.seq_id] += 1
-        nxt = np.asarray(self._sample_tokens(logits[:, -1], reqs))
+        with self.tracer.span("serve.decode", rows=len(reqs),
+                              seq_ids=seq_ids):
+            for r in reqs:
+                # COW barrier: the page this append touches must be exclusive
+                self.pool.ensure_writable(r.seq_id,
+                                          self.pool.lens[r.seq_id] // ps)
+            tokens = jnp.asarray([[r.tokens[-1]] for r in reqs], jnp.int32)
+            tables, lengths = self.pool.batch_tables(seq_ids)
+            self.tracer.note(max_pages=int(tables.shape[1]))
+            caches = [{"attn": self.pool.layer_cache(i, tables, lengths)}
+                      for i in range(self.cfg.n_layers)]
+            with self.tracer.span("serve.forward"):
+                logits, new_caches, _ = forward(self.params, self.cfg, tokens,
+                                                positions=lengths[:, None],
+                                                caches=caches)
+                for i, layer in enumerate(new_caches):
+                    self.pool.writeback(i, layer["attn"])
+            for r in reqs:
+                self.pool.lens[r.seq_id] += 1
+            with self.tracer.span("serve.sample", rows=len(reqs)):
+                nxt = np.asarray(self._sample_tokens(logits[:, -1], reqs))
         self.active = []
         for r, t in zip(reqs, nxt):
             r.tokens.append(int(t))
@@ -598,16 +624,19 @@ class ContinuousBatchingEngine:
         for queued prompts and inter-token latency for running sequences
         both stay bounded regardless of prompt length.
         """
-        self._admit()
-        if self.prefilling:
-            with self._mesh_scope():
-                self._prefill_step()
-        if self.active:
-            if self.drafter is not None:
-                self._spec_step()        # wraps only the verify in the mesh
-            else:
+        with self.tracer.span("serve.step", waiting=len(self.waiting),
+                              prefilling=len(self.prefilling),
+                              active=len(self.active)):
+            self._admit()
+            if self.prefilling:
                 with self._mesh_scope():
-                    self._decode()
+                    self._prefill_step()
+            if self.active:
+                if self.drafter is not None:
+                    self._spec_step()    # wraps only the verify in the mesh
+                else:
+                    with self._mesh_scope():
+                        self._decode()
         return bool(self.active or self.waiting or self.prefilling)
 
     def run(self) -> Dict[int, List[int]]:

@@ -207,3 +207,56 @@ def test_temperature_sampling_runs(model):
     assert toks.shape == (2, 4)
     assert (np.asarray(toks) >= 0).all()
     assert (np.asarray(toks) < cfg.vocab_size).all()
+
+
+def test_engine_spans_tile_prompts_and_match_the_batch(model):
+    """With a tracer, each prompt's prefill chunks tile [matched, len) with
+    no gap, each decode's rows are the batch the step decodes, forward and
+    sample sit inside a lane span, and greedy tokens do not change."""
+    from repro.serving.tracing import Tracer
+    cfg, params = model
+    prefix = jax.random.randint(jax.random.PRNGKey(30), (24,), 0,
+                                cfg.vocab_size)
+    prompts = [jnp.concatenate([prefix, jax.random.randint(
+        jax.random.PRNGKey(31 + i), (n,), 0, cfg.vocab_size)])
+        for i, n in enumerate((13, 5, 30))]
+
+    def serve(tracer):
+        eng = ContinuousBatchingEngine(params, cfg, kv_dtype="int8",
+                                       page_size=8, prefill_chunk=16,
+                                       capacity_tokens=8 * 64, tracer=tracer)
+        sids = [eng.submit(p, 4 + 2 * i) for i, p in enumerate(prompts)]
+        return sids, eng.run()
+
+    tracer = Tracer(enabled=True)
+    sids, traced = serve(tracer)
+    _, plain = serve(None)
+    assert traced == plain
+    spans = tracer.spans
+    by = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    assert len(by["serve.admit"]) == len(prompts)
+    assert [a.attrs["matched"] for a in by["serve.admit"]] == [0, 24, 24]
+    for a, sid, p in zip(by["serve.admit"], sids, prompts):
+        assert a.attrs["seq_id"] == sid and a.attrs["prompt_tokens"] == len(p)
+        chunks = [c.attrs for c in by["serve.prefill_chunk"]
+                  if c.attrs["seq_id"] == sid]
+        edges = [a.attrs["matched"]] + [c["q_start"] + c["tokens"]
+                                        for c in chunks]
+        assert [c["q_start"] for c in chunks] == edges[:-1]
+        assert edges[-1] == len(p)
+        assert [c["last"] for c in chunks] == [False] * (len(chunks) - 1) \
+            + [True]
+    for d in by["serve.decode"]:
+        step = spans[d.parent]
+        assert step.name == "serve.step"
+        finished = sum(1 for c in by["serve.prefill_chunk"]
+                       if c.parent == d.parent and c.attrs["last"])
+        assert d.attrs["rows"] == step.attrs["active"] + finished
+        assert len(d.attrs["seq_ids"]) == d.attrs["rows"]
+        assert d.attrs["max_pages"] >= 1
+    lanes = {"serve.prefill_chunk", "serve.decode"}
+    for s in by["serve.forward"] + by["serve.sample"]:
+        assert spans[s.parent].name in lanes
+    assert all(s.parent == -1 for s in by["serve.step"])
