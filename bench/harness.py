@@ -146,7 +146,8 @@ def prepare_process() -> None:
     """Caches at fixed paths in the checkout, before anything compiles: the
     compilation cache, so that only a cell's first run in a checkout
     compiles, and an autotune cache that starts empty in every run, so every
-    run serves from the same analytic block choices."""
+    run serves from the same analytic block choices. Registers the kernel
+    gate's trace counter before anything is traced."""
     os.makedirs(os.path.dirname(AUTOTUNE_PATH), exist_ok=True)
     if os.path.exists(AUTOTUNE_PATH):
         os.remove(AUTOTUNE_PATH)
@@ -158,6 +159,7 @@ def prepare_process() -> None:
     # builds some thousands of them, so no size cap may evict its own
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_compilation_cache_max_size", -1)
+    kernel_traces()
 
 
 def device_record(chips: int, peaks_table: dict) -> dict:
@@ -423,30 +425,63 @@ def _peak_bytes() -> Optional[int]:
     return max(peaks) if peaks else None
 
 
-def assert_compiled_kernels(qmode: str) -> dict:
-    """Every kernel on the served path resolved to compiled Pallas and ran:
-    the fused CAMP GEMM of ``qmode`` and both paged attention kernels.
-    Returns how many programs each compiled."""
+class KernelTraces:
+    """How often JAX traced each function into a program in this process,
+    by name, from ``jax.monitoring``: JAX reports a jaxpr trace for every
+    ``jax.jit`` function it traces, one called only inside another
+    ``jax.jit`` included, and a program loaded from the persistent cache is
+    traced all the same. A shape traced once is served from JAX's trace
+    cache after that and reported no more, so the counts hold for the
+    whole process: runs take the one :func:`kernel_traces` gives."""
+    EVENT = "/jax/core/compile/jaxpr_trace_duration"
+
+    def __init__(self):
+        import jax
+        self.counts: Dict[str, int] = {}
+        jax.monitoring.register_event_duration_secs_listener(self._on_trace)
+
+    def _on_trace(self, name, secs, fun_name=None, **_):
+        if name == self.EVENT:
+            self.counts[fun_name] = self.counts.get(fun_name, 0) + 1
+
+
+_TRACES: Optional[KernelTraces] = None
+
+
+def kernel_traces() -> KernelTraces:
+    """The process's one :class:`KernelTraces`, registered on first use:
+    :func:`prepare_process` calls it before anything is traced."""
+    global _TRACES
+    if _TRACES is None:
+        _TRACES = KernelTraces()
+    return _TRACES
+
+
+def kernel_resolution() -> dict:
+    """How the served path resolves its kernels on this machine."""
     from repro.kernels import ops
-    from repro.kernels import camp_gemm_fused as fused
     from repro.kernels.backend import interpret_mode, resolve, resolve_paged
-    from repro.kernels.paged_attention import _paged_attention_pallas
-    from repro.kernels.paged_prefill import _paged_prefill_pallas
-    resolved = {"fused_gemm": resolve("auto", ops._VALID),
-                "paged": resolve_paged("auto", True),
-                "interpret": interpret_mode()}
+    return {"fused_gemm": resolve("auto", ops._VALID),
+            "paged": resolve_paged("auto", True),
+            "interpret": interpret_mode()}
+
+
+def assert_compiled_kernels(qmode: str, traces: KernelTraces) -> dict:
+    """Every kernel on the served path resolved to compiled Pallas and was
+    traced into a program the process built, as its own program or inside
+    a larger one: the fused CAMP GEMM of ``qmode`` and both paged attention
+    kernels. Returns how often each was traced."""
+    resolved = kernel_resolution()
     if resolved != {"fused_gemm": "pallas", "paged": "pallas",
                     "interpret": False}:
         raise RuntimeError(f"kernels not compiled Pallas: {resolved}")
-    programs = {
-        "camp_gemm_fused_" + qmode:
-            getattr(fused, "camp_gemm_fused_" + qmode)._cache_size(),
-        "paged_decode": _paged_attention_pallas._cache_size(),
-        "paged_prefill": _paged_prefill_pallas._cache_size()}
-    idle = [k for k, v in programs.items() if v == 0]
+    kernels = ("camp_gemm_fused_" + qmode, "_paged_attention_pallas",
+               "_paged_prefill_pallas")
+    counts = {k: traces.counts.get(k, 0) for k in kernels}
+    idle = [k for k, v in counts.items() if v == 0]
     if idle:
         raise RuntimeError(f"kernels never compiled on the path: {idle}")
-    return programs
+    return counts
 
 
 class CompileCounter:
@@ -497,6 +532,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     device = device_record(int(cell.workload["chips"]), peaks_table)
     peaks = peaks_table["devices"][device["kind"]]
     compiles = CompileCounter()
+    traces = kernel_traces()
     mc = model_config(cell, qmode)
     shape = count_shape(mc)
     clock = time.perf_counter
@@ -557,8 +593,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     finish_first_tokens(drv, counts, clock)
     memory_peak = _peak_bytes()
     if device["platform"] == "tpu":
-        log(f"[bench] compiled Pallas programs "
-            f"{assert_compiled_kernels(mc.qmode)}")
+        log(f"[bench] Pallas kernels traced into programs "
+            f"{assert_compiled_kernels(mc.qmode, traces)}")
 
     for rec in drv.served:                 # host clock -> window-relative s
         rec.token_times = [t - start for t in rec.token_times]

@@ -1,11 +1,12 @@
 """The paged decode kernel's share of its roofline in the traced window:
 per layer and step, the larger of the attention operations over the bf16
 peak and the cached int8 rows, scales, q and out over HBM bandwidth,
-summed, over the kernel program's device time in the trace, %."""
+summed, over the kernel's device time in the trace, in whatever program
+it ran, %."""
 import counting
 import readings
 
-PROGRAMS = ("_paged_attention_pallas",)
+KERNELS = ("_paged_attention_pallas",)
 
 
 def read(run):
@@ -16,4 +17,4 @@ def read(run):
         if st.decode_lengths:
             c = counting.decode_attention(s, st.decode_lengths)
             bound += c.bound_s(peak, bw)     # every layer alike
-    return readings.share(bound, readings.program_seconds(run, PROGRAMS))
+    return readings.share(bound, readings.kernel_seconds(run, KERNELS))

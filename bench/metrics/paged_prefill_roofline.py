@@ -1,11 +1,12 @@
 """The paged prefill kernel's share of its roofline in the traced window:
 per layer and chunk, the larger of the causal attention operations over
 the bf16 peak and the cached prefix, the chunk's rows, q and out over HBM
-bandwidth, summed, over the kernel program's device time, %."""
+bandwidth, summed, over the kernel's device time in the trace, in
+whatever program it ran, %."""
 import counting
 import readings
 
-PROGRAMS = ("_paged_prefill_pallas",)
+KERNELS = ("_paged_prefill_pallas",)
 
 
 def read(run):
@@ -16,4 +17,4 @@ def read(run):
         for q0, c in st.prefill:
             cost = counting.prefill_attention(s, q0, c)
             bound += cost.bound_s(peak, bw)  # every layer alike
-    return readings.share(bound, readings.program_seconds(run, PROGRAMS))
+    return readings.share(bound, readings.kernel_seconds(run, KERNELS))

@@ -38,12 +38,13 @@ def token_gaps_s(run) -> List[float]:
     return gaps
 
 
-def program_seconds(run, prefixes) -> Optional[float]:
-    """Device seconds of the traced programs whose names start with any of
-    ``prefixes``; None when the trace holds none."""
+def kernel_seconds(run, prefixes) -> Optional[float]:
+    """Device seconds of the traced kernels whose names start with any of
+    ``prefixes``, in whatever program they ran; None when the trace holds
+    none."""
     if run.trace is None:
         return None
-    t = sum(v for k, v in run.trace.programs.items()
+    t = sum(v for k, v in run.trace.kernels.items()
             if k.startswith(tuple(prefixes)))
     return t if t > 0 else None
 

@@ -26,7 +26,7 @@ MODEL = {
     # a sound tiny run reads gaps of a few hundredths (the chip limits
     # are set in the real configuration files)
     "correct": {"max_logit_gap": 0.05, "min_tokens": 10,
-                "sample": {"requests": 4, "tokens": 200}},
+                "sample": {"requests": 64, "tokens": 2000}},
     "control": {"weights": "w4a8"},
 }
 MIX = {
