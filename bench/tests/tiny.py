@@ -36,6 +36,12 @@ MIX = {
     "prompt_tokens": {"median": 16, "sigma": 0.5, "min": 4, "max": 64},
     "output_tokens": {"median": 12, "sigma": 0.5, "min": 4, "max": 32},
 }
+# Every request due at once (12 within 17 ms). The engine admits one prompt
+# a step, so the decode batch grows by a row a step until the queue is
+# empty, however long a step takes: a faster engine still batches here.
+BURST = dict(MIX, arrivals={"process": "poisson", "rate_per_s": 1000.0},
+             requests=12,
+             output_tokens={"median": 16, "sigma": 0.5, "min": 8, "max": 32})
 DUMMY_METRIC = '''"""Steps in the window: a metric added by a file alone."""
 
 
