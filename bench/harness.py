@@ -176,7 +176,9 @@ def device_record(chips: int, peaks_table: dict) -> dict:
 
 
 def model_config(cell: Cell, qmode: Optional[str] = None):
-    """The program's ModelConfig for this chip's share of the model."""
+    """The program's ModelConfig of the whole model (a tensor-parallel
+    engine takes the whole model and shards it; ``count_shape`` counts one
+    chip's share)."""
     from repro.models.config import ModelConfig
     ref = cell.reference()
     sh = ref.model_shape(cell.model)
@@ -190,12 +192,64 @@ def model_config(cell: Cell, qmode: Optional[str] = None):
         qmode=qmode or cell.model["serving"]["weights"])
 
 
-def count_shape(mc):
+def tensor_parallel(cell: Cell, mc) -> int:
+    """The tensor-parallel degree the configuration states
+    (``serving.tensor_parallel``, 1 where absent). Refused, before anything
+    is built, where it is not the cell's chip count, or where it does not
+    divide the kv heads: the engine would then quietly run attention
+    replicated on every chip."""
+    tp = int(cell.model["serving"].get("tensor_parallel", 1))
+    chips = int(cell.workload["chips"])
+    if tp != chips:
+        raise ValueError(f"{cell.name}: serving.tensor_parallel {tp} is not "
+                         f"the cell's {chips} chips")
+    if mc.n_kv_heads % tp:
+        raise ValueError(f"{cell.name}: serving.tensor_parallel {tp} does "
+                         f"not divide the {mc.n_kv_heads} kv heads")
+    return tp
+
+
+def _serve_split(mc, tp: int) -> dict:
+    """Whether the serve rules shard d_ff (the gate projection's columns)
+    and the vocabulary (the head's columns; the embedding's rows where the
+    head is tied to it) over a model axis of ``tp``, as they place the
+    weights: read from ``params_pspecs`` over the weights' shapes alone."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AbstractMesh
+    from repro.models.transformer import init_layer, init_top, quantize_params
+    from repro.parallel.sharding import make_rules, params_pspecs
+
+    def weights(keys):
+        p = init_top(keys, mc)
+        p["layers"] = [init_layer(keys[2], mc, mc.mixer_of(0), mc.ffn_of(0))]
+        return quantize_params(p, mc, mc.qmode)
+    shapes = jax.eval_shape(weights, jax.ShapeDtypeStruct((3, 2), jnp.uint32))
+    specs = params_pspecs(shapes, make_rules("serve"),
+                          AbstractMesh((1, tp), ("data", "model")))
+
+    def spec(leaf):
+        return getattr(leaf, "q", leaf)
+    head = (spec(specs["embedding"])[0] if mc.tie_embeddings
+            else spec(specs["lm_head"])[-1])
+    gate = spec(specs["layers"][0]["mlp"]["w_gate"])[-1]
+    return {"d_ff": gate == "model", "vocab": head == "model"}
+
+
+def count_shape(mc, tp: int = 1):
+    """One chip's share of the model as it is run (``counting.Shape``). At
+    degree ``tp`` the heads and kv heads are divided by ``tp``, and d_ff and
+    the vocabulary where the serve rules shard them."""
     from counting import Shape
     bits = 4 if mc.qmode.startswith("w4") else 8
-    return Shape(mc.n_layers, mc.d_model, mc.n_heads, mc.n_kv_heads, mc.hd,
-                 mc.d_ff, mc.vocab_size, bits, mc.tie_embeddings,
-                 mc.qkv_bias)
+    heads, kv, d_ff, vocab = mc.n_heads, mc.n_kv_heads, mc.d_ff, mc.vocab_size
+    if tp > 1:
+        split = _serve_split(mc, tp)
+        heads, kv = heads // tp, kv // tp
+        d_ff = d_ff // tp if split["d_ff"] else d_ff
+        vocab = vocab // tp if split["vocab"] else vocab
+    return Shape(mc.n_layers, mc.d_model, heads, kv, mc.hd, d_ff, vocab, bits,
+                 mc.tie_embeddings, mc.qkv_bias)
 
 
 def seed_key(seed: int):
@@ -203,15 +257,36 @@ def seed_key(seed: int):
     return jax.random.PRNGKey(seed % 2 ** 32)
 
 
-def build_engine(params, mc, cell: Cell, seed: int):
+def serving_mesh(tp: int):
+    """The (data 1, model ``tp``) mesh over the cell's chips, as
+    ``python -m repro.launch.serve --tp`` builds it."""
+    from repro.launch.mesh import make_serving_mesh
+    mesh = make_serving_mesh(tp)
+    if dict(mesh.shape) != {"data": 1, "model": tp}:
+        raise NoChip(f"a tensor-parallel degree of {tp} runs on exactly "
+                     f"{tp} chips, JAX has {mesh.size}")
+    return mesh
+
+
+def build_engine(params, mc, cell: Cell, seed: int, **mesh):
+    """The engine; ``mesh=`` serves over a tensor-parallel mesh, and at
+    degree 1 no mesh is passed at all."""
     from repro.serving.engine import ContinuousBatchingEngine
     import jax
     return ContinuousBatchingEngine(
         params, mc, kv_dtype=cell.model["serving"]["kv_cache"],
         capacity_tokens=int(cell.model["pool"]["capacity_tokens"]),
-        sample="greedy", key=jax.random.PRNGKey(seed % 2 ** 32))
+        sample="greedy", key=jax.random.PRNGKey(seed % 2 ** 32), **mesh)
 
 
+def assert_sharded(eng, tp: int) -> None:
+    """The engine serves at degree ``tp``, its pool sharded over the chips
+    when ``tp`` > 1: a configuration that quietly runs replicated does not
+    read as tensor parallel."""
+    if eng.tp != tp or eng.pool.sharded != (tp > 1):
+        raise RuntimeError(f"engine serves at degree {eng.tp}, pool sharded "
+                           f"{eng.pool.sharded}; the configuration states "
+                           f"{tp}")
 
 
 # ---------------------------------------------------------------------------
@@ -527,21 +602,25 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     from repro.launch.serve import init_serving_params
     import traffic as traffic_mod
 
+    mc = model_config(cell, qmode)
+    tp = tensor_parallel(cell, mc)
     with open(os.path.join(cell.bench_dir, "peaks.json")) as f:
         peaks_table = json.load(f)
     device = device_record(int(cell.workload["chips"]), peaks_table)
     peaks = peaks_table["devices"][device["kind"]]
     compiles = CompileCounter()
     traces = kernel_traces()
-    mc = model_config(cell, qmode)
-    shape = count_shape(mc)
+    shape = count_shape(mc, tp)
     clock = time.perf_counter
 
-    params = init_serving_params(seed_key(seed), mc, mc.qmode)
+    # at degree 1 neither call gets a mesh argument: the one-chip path
+    mesh = {} if tp == 1 else {"mesh": serving_mesh(tp)}
+    params = init_serving_params(seed_key(seed), mc, mc.qmode, **mesh)
     jax.block_until_ready(params)
     t_weights = clock() - t_process
 
-    eng = build_engine(params, mc, cell, seed)
+    eng = build_engine(params, mc, cell, seed, **mesh)
+    assert_sharded(eng, tp)
     reqs = traffic_mod.generate(cell.mix, seed, mc.vocab_size, rate=rate)
     t_warm0 = clock()
     warm = Driver(eng, [])
@@ -612,7 +691,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
     sample = draw_sample(drv, seed, cell.model["correct"]["sample"])
     attempted = sum(1 for r in run.served if counts(r))
     failed = sum(1 for r in run.served if counts(r) and not r.token_times)
-    del drv, eng, params
+    del drv, warm, eng, params       # warm and drv both hold the engine
     gc.collect()
     t_ref0 = clock()
     verdict = compare(cell, seed, sample)

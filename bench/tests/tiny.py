@@ -29,6 +29,12 @@ MODEL = {
                 "sample": {"requests": 64, "tokens": 2000}},
     "control": {"weights": "w4a8"},
 }
+# Served over a tensor-parallel mesh of 4 chips: heads, kv heads, d_ff and
+# the vocabulary all divide by 4, and the head is untied (a CAMP GEMM whose
+# columns shard), so every weight the serve rules split is split.
+TP_MODEL = dict(MODEL, num_attention_heads=8, num_key_value_heads=4,
+                intermediate_size=256, tie_word_embeddings=False,
+                serving=dict(MODEL["serving"], tensor_parallel=4))
 MIX = {
     "arrivals": {"process": "poisson", "rate_per_s": 4.0},
     "requests": 40, "sizes_seed": 3,
@@ -50,8 +56,9 @@ def read(run):
 '''
 
 
-def checkout(tmp: str, model=None, mix=None) -> str:
-    """A checkout under ``tmp`` whose BENCHMARK.json names the tiny cell."""
+def checkout(tmp: str, model=None, mix=None, chips: int = 1) -> str:
+    """A checkout under ``tmp`` whose BENCHMARK.json names the tiny cell, on
+    ``chips`` chips."""
     bench = os.path.join(tmp, "bench")
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
         "tests", "__pycache__"))
@@ -67,7 +74,7 @@ def checkout(tmp: str, model=None, mix=None) -> str:
                             "file": "bench/configs/tiny.json",
                             "reduced": [], "why": "test"})
     spec["workloads"].append({"name": CELL, "config": "tiny",
-                              "traffic": "tiny-chat", "chips": 1,
+                              "traffic": "tiny-chat", "chips": chips,
                               "why": "test"})
     for m in spec["end_to_end"]:
         if "workloads" in m:
@@ -84,12 +91,13 @@ def checkout(tmp: str, model=None, mix=None) -> str:
 
 
 def run(tmp: str, *, seed=7, seconds=2.0, traced=False, qmode=None,
-        log=lambda m: None, model=None, mix=None, full=False):
+        log=lambda m: None, model=None, mix=None, full=False, chips=1,
+        warmup=True):
     """One harness run of the tiny cell on the CPU, past the chip check:
     the result object, or with ``full`` the pair (result, Run)."""
     import time
     import harness
-    root = checkout(tmp, model, mix)
+    root = checkout(tmp, model, mix, chips)
     cell = harness.find_cell(harness.load_benchmark(root), CELL, root)
     real = harness.device_record
     harness.device_record = lambda chips, table: {
@@ -97,7 +105,7 @@ def run(tmp: str, *, seed=7, seconds=2.0, traced=False, qmode=None,
     try:
         out = harness.run_cell(cell, seed, seconds, traced,
                                t_process=time.perf_counter(),
-                               log=log, qmode=qmode)
+                               log=log, qmode=qmode, warmup=warmup)
         return out if full else out[0]
     finally:
         harness.device_record = real
